@@ -286,5 +286,7 @@ if __name__ == "__main__":
                     help="shard the session axis over this many forced "
                          "host devices (ShardedFleetBackend)")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     force_host_devices(args.shards)
     run_all(quick=args.quick, shards=args.shards)

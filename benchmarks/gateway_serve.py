@@ -406,6 +406,8 @@ if __name__ == "__main__":
                          "this many forced host devices; merges into an "
                          "existing BENCH_gateway.json under shards[S]")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     if args.shards:
         from benchmarks.fleet_serve import force_host_devices
         force_host_devices(args.shards)

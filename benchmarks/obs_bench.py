@@ -294,5 +294,7 @@ if __name__ == "__main__":
                     help="tiny CI config: fewest rounds that still "
                          "exercise every assert")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     out = run_all(quick=args.quick, smoke=args.smoke)
     print("wrote", write_bench_json(out))

@@ -36,6 +36,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI config (implies --quick)")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     quick = args.quick or args.smoke
 
     from benchmarks import (cluster_serve, fleet_serve, gateway_serve,

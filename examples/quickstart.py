@@ -12,7 +12,10 @@ import jax
 import numpy as np
 
 from repro.api import FrameRequest, QoSClass, StreamSplitGateway, make_policy
+from repro.launch.cache import enable_compile_cache
 from repro.models.audio_encoder import AudioEncCfg, init_audio_encoder
+
+enable_compile_cache()
 
 # A smoke-scale encoder (the paper's model family, CPU-friendly widths).
 CFG = AudioEncCfg(widths=(16, 16, 32, 32), strides=(1, 2, 1, 2),

@@ -296,7 +296,6 @@ class ShardedFleetBackend(FleetBackend):
                  head_apply=None, cfg: HybridCfg = HybridCfg(), lr=1e-2,
                  seed=0, n_components=0, memory_decay=0.05, mesh=None,
                  axis=SESSIONS_AXIS):
-        from repro.compat import shard_map
         super().__init__()
         if n_components and head_init is None:
             raise ValueError("fleet memory (n_components) updates ride the "
@@ -380,8 +379,8 @@ class ShardedFleetBackend(FleetBackend):
                                   out_shardings=(shd,) * 4)
         pa = P(axis)
         self._insert_placed_fn = jax.jit(
-            shard_map(_ins_placed, mesh=mesh, in_specs=(pa,) * 11,
-                      out_specs=(pa,) * 4, check_vma=False),
+            jax.shard_map(_ins_placed, mesh=mesh, in_specs=(pa,) * 11,
+                          out_specs=(pa,) * 4, check_vma=False),
             donate_argnums=(0, 1, 2, 3))
         self._wipe_fn = jax.jit(_wipe_admit, donate_argnums=(0, 1, 2, 3, 4),
                                 out_shardings=(shd,) * 5)
@@ -446,7 +445,7 @@ class ShardedFleetBackend(FleetBackend):
                 in_specs = (P(), P()) + (P(axis),) * 5
                 out_specs = (P(), P(), P(axis), P())
 
-            self._refine_step = jax.jit(shard_map(
+            self._refine_step = jax.jit(jax.shard_map(
                 local_step, mesh=mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False))
 

@@ -209,7 +209,6 @@ def split_pipeline_podwise(mesh, stage_fn, params_stacked, x_microbatches,
     x_spec = P(None, batch_axes, *([None] * (ndim - 2)))
     in_specs = (x_spec, P("pod"))
     out_specs = x_spec
-    from repro.compat import shard_map
-    return shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)(
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(
         x_microbatches, params_stacked)

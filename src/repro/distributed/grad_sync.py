@@ -59,8 +59,7 @@ def make_compressed_dp_step(mesh, loss_fn, opt_update, *, axis="data",
     def step(params, opt_state, ef, batch):
         structure = jax.tree.structure(batch)
         if structure not in jitted:
-            from repro.compat import shard_map
-            jitted[structure] = jax.jit(shard_map(
+            jitted[structure] = jax.jit(jax.shard_map(
                 local_step, mesh=mesh,
                 in_specs=(P(), P(), P(), batch_spec(batch)),
                 out_specs=(P(), P(), P()),

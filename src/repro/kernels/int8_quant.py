@@ -97,8 +97,10 @@ def wire_roundtrip_pallas(x, *, block_b=8, interpret=True):
     kernel keeps each sample's tile in VMEM through reduce → quantize →
     requantize and writes fp32 once.  ``block_b`` rows ride one grid step
     — (8, 128·m) tiles, the fp32 minimum on TPU.  -> same shape as ``x``,
-    float32, bitwise-equal to the vmapped reference
-    (tests/test_kernels.py pins it in both interpret and compiled modes).
+    float32, bitwise-equal to the vmapped reference in interpret mode
+    (tests/test_kernels.py); tests/test_tpu_compile.py compiles it for a
+    TPU v5e, and ``chip_smoke.py`` checks it against that reference on
+    the chip.
     """
     B = x.shape[0]
     shape = x.shape

@@ -1,12 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels: shape padding, block-size
-selection, and kernel/ref dispatch.  ``interpret=True`` executes the
-kernel bodies on CPU for validation; on TPU pass ``interpret=False`` (or
-run the whole process with ``REPRO_PALLAS_INTERPRET=0`` — the
-compiled-backend CI lane does exactly that, see ``.github/workflows``).
+selection, and kernel/ref dispatch.  A wrapper called without
+``interpret=`` compiles its kernel for the backend it is traced on:
+Mosaic on a TPU, the Pallas interpreter on the CPU (whose backend cannot
+compile Pallas).  Tests may pass ``interpret=`` explicitly;
+``tests/test_tpu_compile.py`` compiles the main-path kernel for a
+described TPU v5e.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -22,44 +23,10 @@ from repro.kernels.laplacian_energy import laplacian_energy_pallas
 from repro.kernels.swd_kernel import swd_pallas
 
 
-# Process-level backend switch for every wrapper below: callers that do
-# not pass ``interpret=`` explicitly get this default, so one env var
-# flips the whole suite between interpret mode (the CPU default) and the
-# compiled Pallas backend (TPU/GPU runners).  Read once at import — a
-# process-level switch, not a per-call one — and ``default_interpret``
-# reports that same snapshot so probes can never disagree with what the
-# wrappers actually resolve to.
-_DEFAULT_INTERPRET = os.environ.get(
-    "REPRO_PALLAS_INTERPRET", "1").lower() not in ("0", "false", "no")
-_COMPILED_OK: bool | None = None
-
-
-def default_interpret() -> bool:
-    return _DEFAULT_INTERPRET
-
-
 def _resolve(interpret):
-    return _DEFAULT_INTERPRET if interpret is None else interpret
-
-
-def compiled_backend_supported() -> bool:
-    """Probe (once) whether this jax backend can *compile* Pallas kernels
-    — CPU-only jaxlibs support interpret mode only, so the compiled CI
-    lane self-skips there (``tests/test_kernels.py``).
-
-    Only the CPU backend may swallow the probe failure: on an
-    accelerator, a failing compile is exactly the regression the
-    compiled lane exists to catch, so it propagates."""
-    global _COMPILED_OK
-    if _COMPILED_OK is None:
-        try:
-            int8_quantize(jnp.ones((8,), jnp.float32), interpret=False)
-            _COMPILED_OK = True
-        except Exception:
-            if jax.default_backend() != "cpu":
-                raise
-            _COMPILED_OK = False
-    return _COMPILED_OK
+    """``None`` -> interpret on the CPU backend only.  Runs at trace time,
+    so each compiled entry matches the backend it was built for."""
+    return jax.default_backend() == "cpu" if interpret is None else interpret
 
 
 def _pad_rows(x, mult, value=0.0):

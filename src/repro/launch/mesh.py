@@ -9,23 +9,28 @@ import os
 
 import jax
 
-from repro.compat import make_mesh as _mk
-
 # process-level latch: jax.distributed.initialize may run at most once
 _distributed = {"initialized": False}
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axis types: jax's default is Explicit,
+    and every mesh in this repo relies on sharding propagation."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (one 256-chip v5e pod) or 2x16x16 (two pods, 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for multi-device CPU tests (subprocesses set
     --xla_force_host_platform_device_count accordingly)."""
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_sessions_mesh(n_shards=None, *, axis=None):
@@ -37,7 +42,7 @@ def make_sessions_mesh(n_shards=None, *, axis=None):
     multi-shard tests and benchmarks)."""
     from repro.distributed.sharding import SESSIONS_AXIS
     n = len(jax.devices()) if n_shards is None else n_shards
-    return _mk((n,), (axis or SESSIONS_AXIS,))
+    return make_mesh((n,), (axis or SESSIONS_AXIS,))
 
 
 def maybe_init_distributed(*, env=None, initialize=None) -> bool:

@@ -249,8 +249,7 @@ def moe_ep(p, moe_cfg, x, *, cap_factor=1.25):
         P("model", None, None),                                 # w_down
     )
     out_specs = (x_spec, P())
-    from repro.compat import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(x, p["router"]["w"], p["w_up"], w_gate, p["w_down"])

@@ -8,7 +8,7 @@ CODE = """
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count={devices}'
 import jax
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.dryrun import build_and_compile
 mesh = make_mesh({mesh_shape}, {mesh_axes})
 rec = build_and_compile('{arch}', '{shape}', mesh, overrides={overrides})

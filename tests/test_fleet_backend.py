@@ -449,7 +449,6 @@ def test_least_loaded_shard_placement_on_admit(subproc):
 _SHARDED_ESTIMATOR_HOOKS = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.core.gmm import em_update, init_gmm
 from repro.core.swd import swd_loss
 from repro.launch.mesh import make_sessions_mesh
@@ -459,7 +458,7 @@ key = jax.random.PRNGKey(0)
 z = jax.random.normal(jax.random.PRNGKey(1), (64, 16))
 
 # pmean'd SWD: the sharded estimator averages per-shard local SWDs
-sharded = jax.jit(shard_map(
+sharded = jax.jit(jax.shard_map(
     lambda z: swd_loss(key, z, n_dirs=16, axis_name="sessions"),
     mesh=mesh, in_specs=(P("sessions"),), out_specs=P(),
     check_vma=False))(z)
@@ -469,7 +468,7 @@ np.testing.assert_allclose(float(sharded), np.mean(locals_), rtol=1e-5)
 
 # psum'd GMM stats: distributed EM == global EM on the gathered batch
 st = init_gmm(jax.random.PRNGKey(2), 8, 16)
-upd = jax.jit(shard_map(
+upd = jax.jit(jax.shard_map(
     lambda st, z: em_update(st, z, axis_name="sessions", reseed_frac=0.0),
     mesh=mesh, in_specs=(P(), P("sessions")), out_specs=P(),
     check_vma=False))(st, z)

@@ -93,7 +93,7 @@ def test_distributed_em_matches_single(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import gmm as G
 mesh = make_mesh((4,), ('data',))
 key = jax.random.PRNGKey(0)
@@ -103,7 +103,7 @@ z = z / jnp.linalg.norm(z, axis=-1, keepdims=True)
 ref = G.em_update(st, z, decay=0.1)
 def local(st, z):
     return G.em_update(st, z, decay=0.1, axis_name='data')
-out = jax.jit(shard_map(local, mesh=mesh,
+out = jax.jit(jax.shard_map(local, mesh=mesh,
     in_specs=(P(), P('data')), out_specs=P(), check_vma=False))(st, z)
 for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(out)):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=1e-6)

@@ -1,10 +1,8 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps.
 
-Runs in interpret mode by default; the compiled-backend CI lane re-runs
-the same sweeps with ``REPRO_PALLAS_INTERPRET=0`` so TPU/GPU runners
-validate the *compiled* kernels against the oracles.  On CPU-only
-jaxlibs (which cannot compile Pallas) the forced-compiled run self-skips
-rather than failing the lane.
+The suite runs on the CPU backend, where the wrappers pick Pallas
+interpret mode; compiling the main-path kernel for the chip is
+``tests/test_tpu_compile.py``'s job.
 """
 import jax
 import jax.numpy as jnp
@@ -13,11 +11,6 @@ import pytest
 
 from repro.core.swd import random_directions, sphere_prior_samples
 from repro.kernels import ops, ref
-
-if not ops.default_interpret() and not ops.compiled_backend_supported():
-    pytest.skip("REPRO_PALLAS_INTERPRET=0 but this jax backend only "
-                "supports Pallas interpret mode (CPU)",
-                allow_module_level=True)
 
 
 def _sphere(key, shape):

@@ -123,14 +123,13 @@ def test_int8_psum_wire_accuracy(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.launch.mesh import make_test_mesh
 from repro.optim.compression import int8_psum
 mesh = make_test_mesh((4,), ('data',))
 x = jax.random.normal(jax.random.PRNGKey(0), (4, 128))
 def f(x):
     return int8_psum(x, 'data'), jax.lax.psum(x, 'data')
-got, want = jax.jit(shard_map(f, mesh=mesh, in_specs=P('data'),
+got, want = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P('data'),
     out_specs=(P(), P()), check_vma=False))(x)
 rel = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
 print('rel err', rel)
