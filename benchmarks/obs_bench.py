@@ -32,6 +32,13 @@ books exactly, and with ``sample=1.0`` every shed frame's span must
 end at its ``shed`` stamp.  This is the stepped-clock miniature of the
 cluster's automatic failover dump (tests/test_obs.py pins that end).
 
+**Lane 4 — tick-phase span cost (reported).**  The gateway's and the
+serving loop's phase spans (``repro.obs.Phases``) are always on.  The
+lane microbenches one ``begin``/``end`` pair with the profiler off and
+under ``jax.profiler.trace``, counts the spans a serving iteration of
+the off lane opened, and reports their cost per iteration against the
+iteration's measured time.
+
     PYTHONPATH=src python -m benchmarks.obs_bench [--quick|--smoke]
 """
 from __future__ import annotations
@@ -124,6 +131,7 @@ def bench_overhead(n=N, *, rounds=16, repeats=3):
         for name in lanes:
             pump(name, t)
     t_base = WARMUP_ROUNDS
+    warm = lanes["off"].stats()             # lane 4's window opens here
     for _ in range(repeats):                # interleaved best-of
         for name in lanes:
             t0 = time.perf_counter()
@@ -157,6 +165,7 @@ def bench_overhead(n=N, *, rounds=16, repeats=3):
         "overhead_budget": OVERHEAD_BUDGET,
         "traces_on": on.tracer.finished,
         "server_off": off,                 # lane 2 exports this stack
+        "window_off": (warm, off.stats()),  # lane 4 reads its phases
     }
 
 
@@ -167,7 +176,8 @@ def bench_export(srv):
     n_samples = validate_prometheus(text)   # raises on any violation
     assert n_samples >= 20, f"suspiciously thin export: {n_samples}"
     for must in ("stream_frames_served", "stream_frames_submitted",
-                 "stream_queue_wait_ms_count", "gateway_stage_ewma_ms"):
+                 "stream_queue_wait_ms_count", "gateway_stage_ewma_ms",
+                 "gateway_phase_ns_total", "stream_phase_calls_total"):
         assert must in text, f"export lost {must}"
     snap = registry_snapshot(srv.registry)
     assert {m["kind"] for m in snap["metrics"]} >= {"counter", "gauge",
@@ -240,11 +250,45 @@ def bench_recorder(*, rounds=24, max_batch=4):
             "counts_exact": True}
 
 
+def _phase_pair_ns(reps):
+    """ns of one ``Phases.begin``/``end`` pair on the real clock."""
+    from repro.obs import MetricsRegistry, Phases
+    ph = Phases(MetricsRegistry(), "bench", ("p",))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        ph.end(ph.begin("p"))
+    return (time.perf_counter() - t0) / reps * 1e9
+
+
+def bench_phases(window, *, reps=20_000):
+    """-> lane-4 dict: the phase spans a warm serving iteration of the
+    off lane opened, and their cost, profiler off and on."""
+    import tempfile
+    a, b = ({**st.gateway.phases, **st.phases} for st in window)
+    calls = {name: b[name][0] - a[name][0] for name in b}
+    steps = calls["stream.step"]
+    step_s = b["stream.step"][1] - a["stream.step"][1]
+    per_step = sum(calls.values()) / steps
+    _phase_pair_ns(reps)                       # warm
+    off = min(_phase_pair_ns(reps) for _ in range(3))
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            on = min(_phase_pair_ns(reps) for _ in range(3))
+    step_ns = step_s / steps * 1e9
+    return {"spans_per_iteration": per_step,
+            "pair_ns": {"off": off, "on": on},
+            "ns_per_iteration": {"off": off * per_step, "on": on * per_step},
+            "iteration_ns": step_ns,
+            "fraction_of_iteration": {"off": off * per_step / step_ns,
+                                      "on": on * per_step / step_ns}}
+
+
 def run_all(*, quick=False, smoke=False):
     result = {}
     rounds = 6 if smoke else (10 if quick else 16)
     o = bench_overhead(N, rounds=rounds, repeats=2 if smoke else 3)
     srv_off = o.pop("server_off")
+    window = o.pop("window_off")
     result["overhead"] = o
     row("obs.off_path_ns_per_frame", o["off_path_ns_per_frame"] * 1e-3,
         f"{o['off_path_fraction_of_frame']:.4%} of a frame "
@@ -258,6 +302,12 @@ def run_all(*, quick=False, smoke=False):
         "schema-validated exposition samples from one serving stack")
     with srv_off.queues.cond:
         pass                               # stack idle; nothing to join
+    p = bench_phases(window, reps=5_000 if smoke else 20_000)
+    result["phases"] = p
+    row("obs.phase_spans_per_iteration", p["ns_per_iteration"]["off"] * 1e-3,
+        f"{p['spans_per_iteration']:.1f} spans, profiler off "
+        f"{p['fraction_of_iteration']['off']:.3%} / on "
+        f"{p['fraction_of_iteration']['on']:.3%} of an iteration")
     r = bench_recorder(rounds=8 if smoke else 24)
     result["recorder"] = r
     row("obs.recorder_shed", r["shed"],

@@ -75,7 +75,12 @@ from repro.core.env import EdgeCloudEnv
 from repro.core.fleet import FleetFullError, HostFleetBackend, pad_pow2
 from repro.core.splitter import SplitEngine
 from repro.core.sync import LazySync, SyncCfg
-from repro.obs import MetricsRegistry, to_prometheus
+from repro.obs import MetricsRegistry, Phases, to_prometheus
+
+# The gateway's tick phases (docs/OBSERVABILITY.md): each is a profiler
+# span ``gateway.<phase>`` and three ``gateway_phase_*_total`` counters.
+PHASES = ("launch", "decide", "stage_h2d", "dispatch", "account",
+          "collect", "sync", "refine", "open_session")
 
 
 class TickPlan:
@@ -254,13 +259,13 @@ class StreamSplitGateway:
         self._routed = {r: R.counter("gateway_routed_frames", route=r)
                         for r in ("edge", "split", "server")}
         self._shard_frames = np.zeros(backend.shards, np.int64)
-        # always-on cheap stage timings: one EWMA multiply-add per tick
-        # (alpha 0.2), so launch/collect/tick spans are a live registry
-        # signal even with profiling off — tick(profile=True) and
-        # last_profile are debug detail now, not the only timing source
-        self._stage_ewma = {
-            stage: R.gauge("gateway_stage_ewma_ms", stage=stage)
-            for stage in ("launch", "collect", "tick")}
+        # always-on phase spans and counters; a StreamServer hands the
+        # phases its flight recorder for slow_phase events
+        self.phases = Phases(R, "gateway", PHASES, clock=clock,
+                             tick=self._ticks)
+        # the tick span as an EWMA (alpha 0.2): resource_signals feeds
+        # it to adaptive policies
+        self._tick_ewma = R.gauge("gateway_stage_ewma_ms", stage="tick")
         self._g_last_tick_ms = R.gauge("gateway_last_tick_ms")
         self._g_syncs = R.gauge("gateway_device_syncs_per_tick")
         self._g_d2h = R.gauge("gateway_d2h_copies_per_tick")
@@ -300,10 +305,15 @@ class StreamSplitGateway:
                      qos: QoSClass = QoSClass.STANDARD) -> SessionInfo:
         """Admit a session into the fleet; raises ``AdmissionError`` (a
         ``FleetFullError``) when its QoS class finds no headroom."""
-        sid = self._admit_row(qos)
-        self._sessions[sid] = _Session(sid, platform, qos, self.sync_cfg)
-        self._opened.inc()
-        return self.session(sid)
+        span = self.phases.begin("open_session")
+        try:
+            sid = self._admit_row(qos)
+            self._sessions[sid] = _Session(sid, platform, qos,
+                                           self.sync_cfg)
+            self._opened.inc()
+            return self.session(sid)
+        finally:
+            self.phases.end(span)
 
     def session(self, sid) -> SessionInfo:
         s = self._require(sid)
@@ -472,6 +482,7 @@ class StreamSplitGateway:
             raise RuntimeError(
                 "tick_launch/tick_collect phase the overlapped data plane; "
                 "construct the gateway with overlap=True")
+        span = self.phases.begin("launch")
         t0 = self._clock()
         pending, self._pending = self._pending, []
         self._tick_syncs = 0
@@ -481,7 +492,7 @@ class StreamSplitGateway:
         if pending:
             self._launch_overlapped(plan, self._decide(pending))
         plan.syncs, plan.d2h = self._tick_syncs, self._tick_d2h
-        self._stage_ewma["launch"].ewma((self._clock() - t0) * 1e3)
+        self.phases.end(span)
         return plan
 
     def tick_collect(self, plan: TickPlan) -> list[FrameResult]:
@@ -497,17 +508,17 @@ class StreamSplitGateway:
                 f"offered, #{self._collect_seq} expected (plans collect "
                 "exactly once, oldest first)")
         self._collect_seq += 1
+        span = self.phases.begin("collect")
         # the per-tick sync scoreboard restarts from THIS plan's launch
         # counts: with another tick launched in between (pipelining), the
         # gateway counters were reset by that launch — a collected tick
         # still reports exactly its own waits/copies
         self._tick_syncs, self._tick_d2h = plan.syncs, plan.d2h
-        t_c0 = self._clock()
         results: list[FrameResult | None] = [None] * len(plan.pending)
         if plan.pending:
             self._collect_overlapped(plan, results)
-        self._stage_ewma["collect"].ewma((self._clock() - t_c0) * 1e3)
         self._finish_tick(plan.t0)
+        self.phases.end(span)
         return results  # type: ignore[return-value]
 
     def _decide(self, pending):
@@ -515,6 +526,7 @@ class StreamSplitGateway:
         indices]} buckets.  Bandwidth is normalized exactly like the
         control-plane env so RL policies see the feature scale they were
         trained on."""
+        span = self.phases.begin("decide")
         bw_norm = EdgeCloudEnv.BW_NORM
         obs = np.array([[f.u, f.cpu, min(f.bandwidth_mbps / bw_norm, 1.0)]
                         for _, f, _ in pending], np.float32)
@@ -523,6 +535,7 @@ class StreamSplitGateway:
         buckets: dict[int, list[int]] = {}
         for i, k in enumerate(ks):
             buckets.setdefault(int(k), []).append(i)
+        self.phases.end(span)
         return buckets
 
     def _finish_tick(self, t0):
@@ -533,13 +546,15 @@ class StreamSplitGateway:
         if (self.backend.can_refine and self.refine_every
                 and self._ticks.value % self.refine_every == 0
                 and self.backend.n_active):
+            span = self.phases.begin("refine")
             key = jax.random.fold_in(self._key, self._refine_rounds.value)
             loss, _, _ = self.backend.refine(key)
             self._refine_rounds.inc()
             self._last_refine_loss = loss
+            self.phases.end(span)
         self._last_tick_ms = (self._clock() - t0) * 1e3
         self._g_last_tick_ms.set(self._last_tick_ms)
-        self._stage_ewma["tick"].ewma(self._last_tick_ms)
+        self._tick_ewma.ewma(self._last_tick_ms)
         self._g_syncs.set(self._tick_syncs)
         self._g_d2h.set(self._tick_d2h)
 
@@ -581,6 +596,7 @@ class StreamSplitGateway:
             return self._launch_sharded(plan, buckets)
         pending, profile = plan.pending, plan.profile
         plan.t_d0 = self._clock()
+        span = self.phases.begin("stage_h2d")
         # (1) stage the whole tick's frames as ONE host->device transfer,
         # repeat-padded to a pow2 row count: a streaming scheduler ticks
         # at arbitrary batch sizes, and every device-side bucket gather
@@ -595,6 +611,8 @@ class StreamSplitGateway:
                                            + mel_host.shape[1:])])
         staged = jax.device_put(mel_host)
         self._staged_h2d.inc(mel_host.nbytes)
+        self.phases.end(span)
+        span = self.phases.begin("dispatch")
         # (2) per-bucket device-side gathers + async dispatch chains
         z_bufs = []
         # frame i -> row in the padded concat; itself pow2-padded (pad
@@ -622,6 +640,8 @@ class StreamSplitGateway:
         # straight out of the padded concat (drops pad rows + un-buckets
         # in the same op)
         plan.z_all = jnp.take(jnp.concatenate(z_bufs), pos, axis=0)
+        self.phases.end(span)
+        span = self.phases.begin("account")
         # (4) host bookkeeping + device-resident fleet scatter, all while
         # the chains are still in flight.  The scatter slices z_all to
         # the real row count — one trivial slice executable per distinct
@@ -635,6 +655,7 @@ class StreamSplitGateway:
             self._ingest_fleet(pending,            # async device scatter
                                plan.z_all[:len(pending)])
         self._sync_accounting(pending, now=plan.t_d0)
+        self.phases.end(span)
 
     def _launch_sharded(self, plan, buckets):
         """The sharded launch half (``shard_dispatch``): same contract as
@@ -661,6 +682,7 @@ class StreamSplitGateway:
             crosses a shard either."""
         pending, profile = plan.pending, plan.profile
         plan.t_d0 = self._clock()
+        span = self.phases.begin("stage_h2d")
         S = self.backend.shards
         sids = np.fromiter((sid for sid, _, _ in pending), np.int64,
                            len(pending))
@@ -679,6 +701,8 @@ class StreamSplitGateway:
             rowmap[idx_s] = base + np.arange(len(idx_s))
         staged = jax.device_put(mel_host, self._staged_sharding)
         self._staged_h2d.inc(mel_host.nbytes)
+        self.phases.end(span)
+        span = self.phases.begin("dispatch")
         by_dev = {sh.device: sh.data for sh in staged.addressable_shards}
         z_blocks = []
         for s in range(S):
@@ -720,6 +744,8 @@ class StreamSplitGateway:
         plan.z_all = jax.make_array_from_single_device_arrays(
             (S * block, self.cfg.d_embed), self._staged_sharding, z_blocks)
         plan.rowmap = rowmap
+        self.phases.end(span)
+        span = self.phases.begin("account")
         for k, idx, wire, _, s in plan.launched:
             self._account_bucket(k, idx, pending, wire, shard=s)
         self.backend.insert_batch_placed(
@@ -731,6 +757,7 @@ class StreamSplitGateway:
             rowmap)
         self._shard_frames += np.bincount(shard, minlength=S)
         self._sync_accounting(pending, now=plan.t_d0)
+        self.phases.end(span)
 
     def _collect_overlapped(self, plan, results):
         """Collect half: THE tick's one device sync + one D2H copy, then
@@ -740,7 +767,9 @@ class StreamSplitGateway:
         (counted) wait — np.asarray would otherwise block uncounted
         inside ``_d2h``."""
         pending = plan.pending
+        span = self.phases.begin("sync")
         z_host = self._d2h(self._block(plan.z_all))
+        self.phases.end(span)
         tick_ms = (self._clock() - plan.t_d0) * 1e3 / len(pending)
         if plan.rowmap is not None:
             # sharded plane: un-block the per-shard layout back into
@@ -942,7 +971,8 @@ class StreamSplitGateway:
             uptime_s=self._clock() - self._t_start,
             last_tick_ms=self._last_tick_ms,
             sessions_exported=self._exported.value,
-            sessions_imported=self._imported.value)
+            sessions_imported=self._imported.value,
+            phases=self.phases.view())
 
     def metrics(self) -> str:
         """The gateway's registry in Prometheus text exposition format

@@ -290,6 +290,10 @@ class GatewayStats:
     # migration is neither an admission decision nor a client departure
     sessions_exported: int = 0
     sessions_imported: int = 0
+    # always-on tick phases (repro.obs.Phases; docs/OBSERVABILITY.md):
+    # span name -> (calls, seconds, slow calls), cumulative, so two
+    # snapshots give any window
+    phases: dict = field(default_factory=dict)
 
     @property
     def frames_per_dispatch(self) -> float:
@@ -341,6 +345,8 @@ class StreamStats:
     #                            between submit and tick admission (shed
     #                            frames sample their terminal wait too)
     gateway: GatewayStats      # the dispatch-plane scoreboard underneath
+    # the serving loop's phases, shaped like GatewayStats.phases
+    phases: dict = field(default_factory=dict)
 
 
 @dataclass(frozen=True)
@@ -425,7 +431,7 @@ class ResourceSignals:
     queue_fill: float          # depth / total capacity, in [0, 1]
     in_flight: int             # frames launched, not yet collected
     wait_p95_ms: float         # p95 submit->admit wait (sketch)
-    stage_ewma_ms: float       # EWMA tick launch+collect span
+    stage_ewma_ms: float       # EWMA tick span, launch to collect
     shed_rate: float           # shed / submitted (cumulative), [0, 1]
     reject_rate: float         # refused / offered at the door, [0, 1]
     throughput_fps: float      # frames served per second of uptime

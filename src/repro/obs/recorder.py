@@ -9,7 +9,8 @@ Two rings and one ledger:
   stale, against which deadline), deadline miss, preemption, retry
   (attempt, error), failover (member, sessions lost), degraded
   refusal, rate limit, queue-full rejection, hang detection, drain
-  stragglers;
+  stragglers, and serving phases that ran 50 ms or longer
+  (``slow_phase``, from ``repro.obs.trace.Phases``);
 - ``counts`` — a cumulative per-kind tally that is **never evicted**.
   The rings are bounded (`deque(maxlen=...)`), so after a long overload
   the oldest sheds fall off the ring — but the acceptance contract
@@ -38,7 +39,7 @@ EVENT_KINDS = (
     "shed", "deadline_miss", "preempt", "requeue", "retry",
     "member_failed", "failover", "member_hung", "degraded_refusal",
     "rate_limited", "queue_full", "lost_in_flight", "drain_straggler",
-    "journal_replay", "migrate_out", "migrate_in",
+    "journal_replay", "migrate_out", "migrate_in", "slow_phase",
 )
 
 

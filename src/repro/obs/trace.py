@@ -1,4 +1,5 @@
-"""Per-frame span tracing on the injected clock.
+"""Per-frame span tracing, and per-tick phase spans, on the injected
+clock.
 
 A sampled frame accumulates one ``FrameTrace``: an ordered list of
 ``(event, t_s, attrs)`` stamps taken at every hop of its life —
@@ -37,7 +38,13 @@ from __future__ import annotations
 
 import time
 
-__all__ = ["FrameTrace", "Tracer", "sampled"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["FrameTrace", "Phases", "SLOW_PHASE_S", "Tracer", "sampled"]
+
+# A phase that lasts this long or longer is counted as slow and leaves a
+# ``slow_phase`` event in the flight recorder (docs/OBSERVABILITY.md).
+SLOW_PHASE_S = 0.050
 
 # Knuth multiplicative hash over a (sid, t) mix; 32-bit phase compared
 # against sample * 2^32.  Pure function — every member/replay agrees.
@@ -172,3 +179,69 @@ class Tracer:
         self.finished += 1
         if self.recorder is not None:
             self.recorder.keep_trace(trace)
+
+
+class _Phase:
+    __slots__ = ("span", "calls", "ns", "slow", "exempt")
+
+    def __init__(self, registry, prefix, name, exempt):
+        self.span = f"{prefix}.{name}"
+        self.calls = registry.counter(f"{prefix}_phase_calls_total",
+                                      phase=name)
+        self.ns = registry.counter(f"{prefix}_phase_ns_total", phase=name)
+        self.slow = registry.counter(f"{prefix}_phase_slow_total",
+                                     phase=name)
+        self.exempt = exempt
+
+
+class Phases:
+    """The named phases of one serving component's tick, always on.
+
+    Each phase is one explicit ``begin``/``end`` pair at the site where
+    its work happens.  Between the two the phase is a
+    ``jax.profiler.TraceAnnotation`` named ``<prefix>.<phase>``, so a
+    profiler trace shows it on the same clock as the device.  ``end``
+    adds to three registry counters, on the component's injected clock:
+    ``<prefix>_phase_calls_total``, ``<prefix>_phase_ns_total``
+    (integer nanoseconds) and ``<prefix>_phase_slow_total``, each
+    labelled ``phase=<phase>``.  A phase of ``SLOW_PHASE_S`` or longer
+    is slow: it bumps the slow counter and, with a ``recorder``, records
+    a ``slow_phase`` event (span name, ms, the ``tick`` counter's value,
+    clock time).  Phases named in ``exempt`` idle by design and are
+    never slow.
+    """
+
+    __slots__ = ("clock", "recorder", "tick", "_phases")
+
+    def __init__(self, registry, prefix, names, *, clock=time.perf_counter,
+                 recorder=None, tick=None, exempt=()):
+        self.clock = clock
+        self.recorder = recorder
+        self.tick = tick                  # a Counter: the component's ticks
+        self._phases = {n: _Phase(registry, prefix, n, n in exempt)
+                        for n in names}
+
+    def begin(self, name):
+        """Open phase ``name``; pass the returned token to ``end``."""
+        ph = self._phases[name]
+        ann = TraceAnnotation(ph.span)
+        ann.__enter__()
+        return ph, ann, self.clock()
+
+    def end(self, token) -> None:
+        ph, ann, t0 = token
+        t1 = self.clock()
+        ann.__exit__(None, None, None)
+        ph.calls.inc()
+        ph.ns.inc(round((t1 - t0) * 1e9))
+        if t1 - t0 >= SLOW_PHASE_S and not ph.exempt:
+            ph.slow.inc()
+            if self.recorder is not None:
+                self.recorder.record(
+                    "slow_phase", t1, phase=ph.span, ms=(t1 - t0) * 1e3,
+                    tick=None if self.tick is None else self.tick.value)
+
+    def view(self) -> dict:
+        """``{span name: (calls, seconds, slow)}``, cumulative."""
+        return {ph.span: (ph.calls.value, ph.ns.value / 1e9, ph.slow.value)
+                for ph in self._phases.values()}

@@ -38,7 +38,7 @@ from repro.api.types import (FrameRequest, QoSClass,
                              QueuedFrameSnapshot, ResourceSignals,
                              ServerSessionSnapshot, SessionInfo,
                              SessionSnapshot, StreamStats)
-from repro.obs import FlightRecorder, Tracer, to_prometheus
+from repro.obs import FlightRecorder, Phases, Tracer, to_prometheus
 from repro.serving.queues import (QoSQueues, QueuedFrame,  # noqa: F401
                                   QueueFullError, RateLimitError,
                                   TokenBucket)
@@ -46,6 +46,11 @@ from repro.serving.scheduler import (SchedulerCfg, TickScheduler,
                                      clamp_weight)
 
 _UNSET = object()          # open_session(rate_limit=...) sentinel
+
+# The serving loop's phases (docs/OBSERVABILITY.md): each is a profiler
+# span ``stream.<phase>`` and three ``stream_phase_*_total`` counters.
+# ``wait`` idles on the queue condition by design, so it is never slow.
+PHASES = ("step", "admit", "stage", "deliver", "wait")
 
 
 class _ServedSession:
@@ -188,6 +193,10 @@ class StreamServer:
         self._rate_limited = {q.value: R.counter(
             "stream_rejected_rate_limited", qos=q.value)
             for q in QoSClass}
+        self.phases = Phases(R, "stream", PHASES, clock=self._clock,
+                             recorder=self.recorder, tick=self._ticks,
+                             exempt=("wait",))
+        gateway.phases.recorder = self.recorder
         self._thread: threading.Thread | None = None
         self._stopping = False
         self._drain_on_stop = True
@@ -533,7 +542,9 @@ class StreamServer:
                                            or not self._drain_on_stop):
                         break
                     if not work:
+                        span = self.phases.begin("wait")
                         self.queues.cond.wait(timeout=0.05)
+                        self.phases.end(span)
                         continue
                 self.step()
             # never leave a launched tick dangling
@@ -566,10 +577,14 @@ class StreamServer:
         5. process session closes whose frames have fully drained.
         """
         with self._step_lock:   # close_session fallbacks may race here
-            return self._step_locked()
+            span = self.phases.begin("step")
+            served = self._step_locked()
+            self.phases.end(span)
+            return served
 
     def _step_locked(self) -> int:
         gw = self.gateway
+        span = self.phases.begin("admit")
         with self.queues.cond:                 # Condition wraps an RLock
             batch = self.scheduler.admit(self.queues, self._clock())
             shed = self.scheduler.pop_shed()
@@ -597,17 +612,18 @@ class StreamServer:
         new_plan = None
         new_classes: list[str] = []
         new_traces: list = []
+        for qf in batch:
+            # already validated/converted at enqueue (validate_mel on
+            # the client's thread) — skip the re-check here
+            gw.submit_validated(qf.sid, qf.frame)
+            new_classes.append(qf.qos.value)
+            new_traces.append(qf.trace)
+        self.phases.end(span)
         served = 0
         if batch:
             if self._plan is not None and (not self.pipeline
                                            or gw.refine_due_next_tick()):
                 served += self._collect()
-            for qf in batch:
-                # already validated/converted at enqueue (validate_mel
-                # on the client's thread) — skip the re-check here
-                gw.submit_validated(qf.sid, qf.frame)
-                new_classes.append(qf.qos.value)
-                new_traces.append(qf.trace)
             if self._plan is not None:
                 with self._lock:               # stats() reads under _lock
                     self._pipelined_ticks.inc()
@@ -622,7 +638,9 @@ class StreamServer:
                         if tr is not None:
                             tr.add("dispatch", now, k=int(k),
                                    shard=int(sh))
+        span = self.phases.begin("stage")
         self.scheduler.stage(self.queues, self._clock())
+        self.phases.end(span)
         if self._plan is not None:
             served += self._collect()
         self._plan, self._plan_classes = new_plan, new_classes
@@ -635,6 +653,7 @@ class StreamServer:
         traces = self._plan_traces
         self._plan, self._plan_classes, self._plan_traces = None, [], []
         results = self.gateway.tick_collect(plan)
+        span = self.phases.begin("deliver")
         now = None          # lazy: no clock read unless a trace is live
         for r, tr in zip(results, traces):
             if tr is not None:
@@ -664,6 +683,7 @@ class StreamServer:
                 except Exception:       # user code must not kill serving
                     import traceback
                     traceback.print_exc()
+        self.phases.end(span)
         return len(results)
 
     def _closes_pending(self) -> bool:
@@ -751,6 +771,7 @@ class StreamServer:
                                 for c, m in self._rate_limited.items()}
                 ticks = self._ticks.value
                 pipelined = self._pipelined_ticks.value
+                phases = self.phases.view()
         t = self._thread
         return StreamStats(
             running=t is not None and t.is_alive(),
@@ -768,7 +789,8 @@ class StreamServer:
             promoted=promoted,
             deadline_misses=misses,
             queue_wait_ms=waits,
-            gateway=self.gateway.stats())
+            gateway=self.gateway.stats(),
+            phases=phases)
 
     def metrics(self) -> str:
         """The whole stack's registry in Prometheus text exposition

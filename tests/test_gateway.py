@@ -422,16 +422,20 @@ def test_injected_clock_makes_timing_deterministic(params):
     rng = np.random.default_rng(7)
     gw.submit(sid, FrameRequest(t=0, mel=_mel(rng)))
     (r,) = gw.tick()
-    # dispatch stamps t_d0 at read 2 and closes the span at read 5 (the
-    # always-on launch/collect EWMA stage gauges stamp reads 3 and 4 in
-    # between): (2.5 - 1.0) * 1e3 / bucket = 1500ms
-    assert r.latency_ms == 1500.0
+    # clock reads: ctor(0), the open_session phase(1, 2), the launch
+    # phase(3, 14) around tick entry(4), the decide phase(5, 6), dispatch
+    # start(7), the stage_h2d/dispatch/account phases(8..13), the
+    # collect phase(15, 20) around the sync phase(16, 17), the latency
+    # stamp(18) and tick exit(19), stats(21).
+    # dispatch start(7) to the latency stamp(18): 5.5 s for one frame
+    assert r.latency_ms == 5500.0
     s = gw.stats()
-    # tick entry(1) .. exit(7) around dispatch + EWMA stamps: 3.0 s
-    assert s.last_tick_ms == 3000.0
-    # reads: ctor(0), entry(1), dispatch(2,5), launch/collect EWMA
-    # stamps(3,4,6), tick exit(7), stats(8)
-    assert s.uptime_s == 0.5 * 8
+    # tick entry(4) .. exit(19): 7.5 s
+    assert s.last_tick_ms == 7500.0
+    assert s.uptime_s == 0.5 * 21
+    # every phase span is on the same clock: launch 11 reads, decide 1
+    assert s.phases["gateway.launch"] == (1, 5.5, 1)
+    assert s.phases["gateway.decide"] == (1, 0.5, 1)
 
 
 def test_gateway_on_sharded_backend_bit_matches_host(params):

@@ -483,9 +483,15 @@ def test_stats_views_bit_reproducible_and_conserved(params):
 def test_stage_ewma_always_on_without_profile(params):
     srv = _run_workload(params)
     R = srv.registry
-    for stage in ("launch", "collect", "tick"):
-        assert R.value("gateway_stage_ewma_ms", stage=stage) >= 0.0
-        assert R.get("gateway_stage_ewma_ms", stage=stage) is not None
+    # the tick span's EWMA stays (resource_signals feeds it to policies)
+    tick = R.get("gateway_stage_ewma_ms", stage="tick")
+    assert tick is not None and tick.value > 0.0
+    assert srv.resource_signals().stage_ewma_ms == tick.value
+    # launch and collect are phase counters now, not EWMA gauges
+    for stage in ("launch", "collect"):
+        assert R.get("gateway_stage_ewma_ms", stage=stage) is None
+        assert R.value("gateway_phase_calls_total", phase=stage) \
+            == srv.stats().ticks
     # the profile knob is a debug detail now, not the only timing source
     assert srv.gateway.last_profile is None
 
