@@ -20,8 +20,13 @@ The run fails (non-zero exit) when any of these does not hold:
 - every served embedding is within ``TOL`` of a plain float32 reference
   (``encode()`` per frame under ``default_matmul_precision("highest")``
   with the same per-sample int8 round-trip at the same k, in jnp);
-- the wire stage's lowered text holds ``tpu_custom_call`` (Mosaic, not
-  the Pallas interpreter).
+- the split engine's one executable at an interior k, lowered, holds
+  ``tpu_custom_call``: the wire kernel is Mosaic, not the Pallas
+  interpreter;
+- at k=0 and at an interior k, that one executable (what
+  ``run_batch_async`` launches) equals the edge stage, the wire kernel
+  and the server stage launched one by one: bitwise on the TPU, within
+  ``TOL`` on the CPU, where the interpreted kernel may round otherwise.
 
 Whether served embeddings are bitwise equal to per-frame
 ``SplitEngine.run`` is printed, not asserted.
@@ -242,24 +247,55 @@ def compare(name, got, want):
     return err
 
 
-def wire_is_compiled(cfg, params):
-    """Lower the wire stage as the engine calls it (no ``interpret=``)
-    at an interior boundary; its text must hold the Mosaic custom call."""
+def wire_is_compiled(engine, cfg, params):
+    """Lower the split engine's one executable at an interior boundary
+    as ``run_batch_async`` launches it (edge stage, wire kernel, server
+    stage); its text must hold the wire kernel's Mosaic custom call."""
     import jax
     import jax.numpy as jnp
-    from repro.kernels import ops
-    from repro.models import audio_encoder as enc
     mel = jax.ShapeDtypeStruct((MAX_BATCH, cfg.frames, cfg.n_mels),
                                jnp.float32)
     k = cfg.n_blocks // 2
-    act = jax.eval_shape(lambda p, m: enc.encode(cfg, p, m, end=k),
-                         params, mel)
-    text = ops.wire_roundtrip.lower(act).as_text()
+    text = engine._chain_exec(k).lower(params, mel).as_text()
     ok = "tpu_custom_call" in text
-    print(f"wire stage at k={k} {act.shape}: lowered text holds "
+    print(f"split chain at k={k} {mel.shape}: lowered text holds "
           f"tpu_custom_call: {ok}", flush=True)
     check(ok, "the wire stage lowered without tpu_custom_call "
           "(Pallas interpreter on the chip)")
+
+
+def fused_vs_stages(engine, cfg, params, max_batch):
+    """``run_batch_async``'s one executable against the edge stage, the
+    wire kernel and the server stage launched one by one, on the same
+    ``max_batch`` frames at k=0 and at an interior k.  Fails past
+    ``TOL``, and on the TPU on any bit; -> (frames bitwise equal, max
+    abs difference)."""
+    import jax
+    import numpy as np
+    from repro.kernels import ops
+    mel = jax.random.normal(jax.random.PRNGKey(SEED + 1),
+                            (max_batch, cfg.frames, cfg.n_mels))
+    equal, worst = 0, 0.0
+    for k in (0, cfg.n_blocks // 2):
+        fused = np.asarray(engine.run_batch_async(params, mel, k)[0])
+        act = mel if k == 0 else engine._edge_exec(k)(params, mel)
+        apart = np.asarray(engine._server_exec(k)(params,
+                                                  ops.wire_roundtrip(act)))
+        same = int((fused == apart).all(axis=1).sum())
+        diff = float(np.abs(fused - apart).max())
+        print(f"one executable vs three launches at k={k}: {same} of "
+              f"{max_batch} frames bitwise equal (max abs difference "
+              f"{diff!r})", flush=True)
+        check(diff <= TOL, f"the fused split chain at k={k} is {diff} off "
+              "its three stages launched one by one")
+        # the Mosaic kernel is sealed inside the fused module, and on the
+        # chip the two paths have matched bitwise; the CPU's interpreted
+        # kernel compiles anew there and may differ in the last bit
+        check(jax.default_backend() != "tpu" or same == max_batch,
+              f"the fused split chain at k={k} is not bitwise equal to its "
+              "three stages launched one by one on the TPU")
+        equal, worst = equal + same, max(worst, diff)
+    return equal, worst
 
 
 def serve_one_chip(devices, cfg, *, capacity=CAPACITY, clients=CLIENTS,
@@ -289,7 +325,9 @@ def serve_one_chip(devices, cfg, *, capacity=CAPACITY, clients=CLIENTS,
     print(f"set-up: {warm_s:.3f} s to build the fleet and compile "
           f"{len(policy.ks)} split points x pow2 buckets <= {max_batch}",
           flush=True)
-    wire_is_compiled(cfg, params)
+    wire_is_compiled(gw.engine, cfg, params)
+    fused_equal, fused_diff = fused_vs_stages(gw.engine, cfg, params,
+                                              max_batch)
 
     population = make_clients(cfg, clients, per_client)
     errors = []
@@ -372,7 +410,8 @@ def serve_one_chip(devices, cfg, *, capacity=CAPACITY, clients=CLIENTS,
     print(f"compile: {clock.seconds:.3f} s in {clock.count} XLA compiles "
           "(set-up and first use; no timed window)", flush=True)
     return {"max_abs_error": err, "bitwise_equal": same,
-            "frames": len(served), "refine_losses": losses}
+            "frames": len(served), "refine_losses": losses,
+            "fused_bitwise_equal": fused_equal, "fused_max_abs": fused_diff}
 
 
 def serve_sharded(devices, cfg, *, capacity=CAPACITY, clients=CLIENTS,

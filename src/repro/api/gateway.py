@@ -91,7 +91,7 @@ class TickPlan:
     the NEXT tick under this one's chains (cross-tick pipelining)."""
 
     __slots__ = ("pending", "t0", "profile", "launched", "z_all", "t_d0",
-                 "syncs", "d2h", "seq", "rowmap")
+                 "syncs", "d2h", "seq", "rowmap", "engine_launches")
 
     def __init__(self, pending, t0, profile=False, seq=0):
         self.pending = pending     # [(sid, FrameRequest, mel f32)] served
@@ -104,6 +104,7 @@ class TickPlan:
         self.d2h = 0
         self.seq = seq             # launch order — collect must match
         self.rowmap = None         # sharded plane: submission idx -> row
+        self.engine_launches = 0   # SplitEngine executables this tick
 
     def __len__(self):
         return len(self.pending)
@@ -275,6 +276,10 @@ class StreamSplitGateway:
         self._staged_h2d = R.counter("gateway_staged_h2d_bytes")
         self._tick_syncs = 0
         self._tick_d2h = 0
+        # split-engine executables launched: the tick's delta of
+        # SplitEngine.launches (one per k-bucket on the overlapped plane)
+        self._engine_launches = R.counter("gateway_engine_launches")
+        self._tick_launches = 0
         # launch/collect sequence numbers: plans MUST collect in launch
         # order (the fleet rings see launch-order scatters) — a
         # violation raises instead of silently corrupting parity
@@ -458,10 +463,13 @@ class StreamSplitGateway:
         self._tick_dev: list = []     # (bucket idx, device z) per dispatch
         self._tick_syncs = 0
         self._tick_d2h = 0
+        n0 = self.engine.launches
         if pending:
             for k, idx in sorted(self._decide(pending).items()):
                 self._dispatch(k, idx, pending, results)
             self._ingest(pending, results, now=t0)
+        self._tick_launches = self.engine.launches - n0
+        self._engine_launches.inc(self._tick_launches)
         self._finish_tick(t0)
         return results  # type: ignore[return-value]
 
@@ -489,9 +497,12 @@ class StreamSplitGateway:
         self._tick_d2h = 0
         plan = TickPlan(pending, t0, profile, seq=self._launch_seq)
         self._launch_seq += 1
+        n0 = self.engine.launches
         if pending:
             self._launch_overlapped(plan, self._decide(pending))
         plan.syncs, plan.d2h = self._tick_syncs, self._tick_d2h
+        plan.engine_launches = self.engine.launches - n0
+        self._engine_launches.inc(plan.engine_launches)
         self.phases.end(span)
         return plan
 
@@ -514,6 +525,7 @@ class StreamSplitGateway:
         # gateway counters were reset by that launch — a collected tick
         # still reports exactly its own waits/copies
         self._tick_syncs, self._tick_d2h = plan.syncs, plan.d2h
+        self._tick_launches = plan.engine_launches
         results: list[FrameResult | None] = [None] * len(plan.pending)
         if plan.pending:
             self._collect_overlapped(plan, results)
@@ -967,6 +979,7 @@ class StreamSplitGateway:
             ingest_h2d_bytes=self.backend.ingest_h2d_bytes,
             device_syncs_per_tick=self._tick_syncs,
             d2h_copies_per_tick=self._tick_d2h,
+            engine_launches_per_tick=self._tick_launches,
             staged_h2d_bytes=self._staged_h2d.value,
             uptime_s=self._clock() - self._t_start,
             last_tick_ms=self._last_tick_ms,

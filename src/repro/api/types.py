@@ -277,6 +277,9 @@ class GatewayStats:
     # blocks on its own loss read outside this scoreboard.
     device_syncs_per_tick: int = 0   # dispatch-plane waits, last tick
     d2h_copies_per_tick: int = 0     # embedding D2H copies, last tick
+    # SplitEngine executables the last tick launched: one per k-bucket
+    # (per (shard, k) when sharded) on the overlapped plane
+    engine_launches_per_tick: int = 0
     staged_h2d_bytes: int = 0  # cumulative mel bytes staged host->device
     # deterministic under an injected clock= (see StreamSplitGateway)
     uptime_s: float = 0.0      # clock() - clock() at construction

@@ -29,8 +29,9 @@ class SplitEngine:
     """Compiled-per-k split executor for the audio encoder.
 
     Per-k executables are built lazily on first use: a session that only
-    ever runs one k compiles 2 callables, not ``2·(L+1)`` — this is what
-    keeps ``StreamSplitGateway`` startup O(1) in L.  Atomic-transition
+    ever runs one k compiles 2 callables through ``run`` and 1 through
+    ``run_batch_async``, not ``2·(L+1)`` — this is what keeps
+    ``StreamSplitGateway`` startup O(1) in L.  Atomic-transition
     semantics are unchanged: each k still gets its own executable, and
     switching k selects a whole different compiled program at a step
     boundary, never mid-block.
@@ -41,16 +42,33 @@ class SplitEngine:
         self.quantize_wire = quantize_wire
         self._edge = {}
         self._server = {}
-        # The INT8 wire round-trip runs as its OWN jitted executable,
-        # never fused into the edge/server stages: fusing it changes the
-        # rounding of the affine chain, and the per-frame vs k-bucketed
-        # bit-parity contract (tests/test_gateway.py) depends on both
-        # paths quantizing with the same compiled program.  ``run``
-        # quantizes per tensor (one scale/zero for its whole batch);
-        # ``run_batch`` per sample — identical at B=1, which is exactly
-        # the parity boundary.
+        self._chain = {}
+        self._wire = {}           # (k, mel shape) -> wire bytes per frame
+        self.launches = 0         # executables invoked (``_launch``)
+        # ``run`` and ``run_batch`` are the references: their jnp INT8
+        # wire round-trip runs as its OWN jitted executable, never fused
+        # into the edge/server stages, because XLA may fuse jnp arithmetic
+        # across the stage boundary and so round the affine chain
+        # differently.  ``run`` quantizes per tensor (one scale/zero for
+        # its whole batch); ``run_batch`` per sample — identical at B=1,
+        # which is exactly the parity boundary.  ``run_batch_async``
+        # fuses edge -> wire -> server into one executable: its wire stage
+        # is the Pallas ``wire_roundtrip`` kernel, an opaque Mosaic custom
+        # call on the TPU, so no fusion reaches the kernel's own
+        # arithmetic.  The stages around it (and, on the CPU, the
+        # interpreted kernel) are compiled anew inside the larger module,
+        # so the one executable may differ from the three launches in the
+        # last bit: tests/test_splitter_env.py pins equality on fixed
+        # inputs on the CPU, and ``chip_smoke.py`` asserts it bitwise on
+        # the TPU, where the Mosaic kernel is sealed.
         self._qdq_tensor = jax.jit(lambda a: dequantize(quantize(a)))
         self._qdq_sample = jax.jit(jax.vmap(lambda a: dequantize(quantize(a))))
+
+    def _launch(self, exe, *args):
+        """Invoke one compiled executable and count it once dispatched."""
+        out = exe(*args)
+        self.launches += 1
+        return out
 
     def _edge_exec(self, k):
         if k not in self._edge:
@@ -61,6 +79,32 @@ class SplitEngine:
         if k not in self._server:
             self._server[k] = jax.jit(partial(self._server_fn, k))
         return self._server[k]
+
+    def _chain_exec(self, k):
+        """The one executable ``run_batch_async`` launches at k: the edge
+        stage, the wire kernel and the server stage for k < L, and the
+        edge-only program at k = L."""
+        if k not in self._chain:
+            self._chain[k] = (self._edge_exec(k) if k == self.cfg.n_blocks
+                              else jax.jit(partial(self._chain_fn, k)))
+        return self._chain[k]
+
+    def _chain_fn(self, k, params, mel):
+        act = self._edge_fn(k, params, mel)
+        if self.quantize_wire:
+            act = kernel_ops.wire_roundtrip(act)
+        return self._server_fn(k, params, act)
+
+    def _wire_bytes(self, k, params, mel):
+        """Wire bytes per frame of a k < L bucket, from the boundary's
+        shape alone (traced once per (k, mel shape), never launched)."""
+        key = (k, mel.shape)
+        if key not in self._wire:
+            act = jax.eval_shape(partial(self._edge_fn, k), params, mel)
+            per_frame = act.size // act.shape[0]
+            self._wire[key] = (per_frame + 8 if self.quantize_wire
+                               else per_frame * 4)
+        return self._wire[key]
 
     def _edge_fn(self, k, params, mel):
         if k == 0:
@@ -84,14 +128,14 @@ class SplitEngine:
         L = self.cfg.n_blocks
         k = int(k)
         if k >= L:
-            return self._edge_exec(L)(params, mel), 0
-        act = self._edge_exec(k)(params, mel)
+            return self._launch(self._edge_exec(L), params, mel), 0
+        act = self._launch(self._edge_exec(k), params, mel)
         if self.quantize_wire:
             wire_bytes = act.size + 8     # int8 payload + scale/zero header
-            act = self._qdq_tensor(act)   # "received" on the server
+            act = self._launch(self._qdq_tensor, act)  # "received"
         else:
             wire_bytes = act.size * 4
-        z = self._server_exec(k)(params, act)
+        z = self._launch(self._server_exec(k), params, act)
         return z, wire_bytes
 
     def run_batch(self, params, mel, k):
@@ -110,15 +154,15 @@ class SplitEngine:
         L = self.cfg.n_blocks
         k = int(k)
         if k >= L:
-            return self._edge_exec(L)(params, mel), 0
-        act = self._edge_exec(k)(params, mel)
+            return self._launch(self._edge_exec(L), params, mel), 0
+        act = self._launch(self._edge_exec(k), params, mel)
         per_frame = act.size // act.shape[0]
         if self.quantize_wire:
-            act = self._qdq_sample(act)
+            act = self._launch(self._qdq_sample, act)
             wire_bytes = per_frame + 8    # int8 payload + scale/zero header
         else:
             wire_bytes = per_frame * 4
-        z = self._server_exec(k)(params, act)
+        z = self._launch(self._server_exec(k), params, act)
         return z, wire_bytes
 
     def run_batch_async(self, params, mel, k):
@@ -129,32 +173,22 @@ class SplitEngine:
         buckets overlap on the device instead of paying one round-trip
         each.
 
-        The wire stage runs the fused Pallas ``wire_roundtrip`` kernel
-        (``kernels/int8_quant.py``) — still its OWN executable, never
-        fused into the edge/server stages, and pinned bitwise against the
-        vmapped ``quantize∘dequantize`` reference that ``run_batch``
-        executes — so embeddings stay bit-identical to both the PR-3 sync
-        path and B separate ``run`` calls.
+        ONE launch for every k: edge stage, the Pallas ``wire_roundtrip``
+        kernel (``kernels/int8_quant.py``) and server stage compiled as
+        one executable per (k, batch shape).  The kernel is pinned bitwise
+        against the vmapped ``quantize∘dequantize`` reference that
+        ``run_batch`` executes; the fused executable matches the three
+        stages launched one by one to float32 rounding (see
+        ``__init__``).  -> (z (B, d), wire bytes per frame), the bytes
+        computed on the host from the boundary shape.
         """
         L = self.cfg.n_blocks
         k = int(k)
-        if k >= L:
-            return self._edge_exec(L)(params, mel), 0
-        # k=0 offloads the raw input: _edge_fn(0) is the identity, so the
-        # dispatch skips its executable entirely (bitwise no-op, one less
-        # host->device program launch on the hot path)
-        act = mel if k == 0 else self._edge_exec(k)(params, mel)
-        per_frame = act.size // act.shape[0]
-        if self.quantize_wire:
-            act = kernel_ops.wire_roundtrip(act)
-            wire_bytes = per_frame + 8    # int8 payload + scale/zero header
-        else:
-            wire_bytes = per_frame * 4
-        z = self._server_exec(k)(params, act)
-        return z, wire_bytes
+        z = self._launch(self._chain_exec(min(k, L)), params, mel)
+        return z, 0 if k >= L else self._wire_bytes(k, params, mel)
 
     def full(self, params, mel):
-        return self._edge_exec(self.cfg.n_blocks)(params, mel)
+        return self._launch(self._edge_exec(self.cfg.n_blocks), params, mel)
 
 
 # ---------------------------------------------------------------------------

@@ -99,7 +99,11 @@ def wire_roundtrip(x, *, block_b=8, interpret=None):
     dim — the split-link wire stage of ``SplitEngine.run_batch_async``.
     Bitwise-equal to ``jax.vmap(lambda a: dequantize(quantize(a)))``
     (pinned in tests/test_kernels.py), so the per-frame vs bucketed
-    bit-parity contract survives the fusion."""
+    bit-parity contract survives the fusion.  The engine traces it
+    inside one executable with the edge and server stages: on the TPU
+    it lowers to a Mosaic custom call, whose own arithmetic no XLA
+    fusion reaches.  The jnp reference is not so sealed and runs
+    un-fused (``run_batch``)."""
     return wire_roundtrip_pallas(x, block_b=block_b,
                                  interpret=_resolve(interpret))
 

@@ -46,6 +46,8 @@ def test_one_chip_phase_at_small_width(monkeypatch):
                                **SIZES)
     assert out["frames"] == 12 * 8
     assert out["max_abs_error"] <= 1e-5          # f32 end to end on CPU
+    # one executable against three launches, at k=0 and an interior k
+    assert out["fused_max_abs"] <= 1e-5
     assert len(out["refine_losses"]) >= 2
 
 

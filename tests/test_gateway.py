@@ -264,6 +264,68 @@ def test_pipelined_phase_ticks_bit_match_sequential(params):
         np.testing.assert_array_equal(a, b)
 
 
+class TierPolicy:
+    """Three split tiers like the chip cells' policy: frame i gets
+    k = (L, L//2, 0)[i % 3] — every tick of >= 3 frames holds three
+    k-buckets."""
+
+    def __init__(self, L):
+        self.L = L
+        self.ks = np.array([L, L // 2, 0], np.int64)
+
+    def decide(self, obs_batch):
+        return self.ks[np.arange(len(obs_batch)) % 3]
+
+
+@pytest.mark.parametrize("plane,per_tick", [
+    ("overlapped", 3), ("sharded", 3), ("sync", 1 + 3 + 3)])
+def test_engine_launches_per_tick_three_k_tick(params, plane, per_tick):
+    """A tick with k in {L, L//2, 0} launches ONE split-engine executable
+    per k-bucket on the overlapped plane, and per (shard, k) on the
+    sharded plane; the ``overlap=False`` reference launches the edge, wire
+    and server stages one by one (1 at k=L, 3 below).  The registry
+    counter adds each tick's count."""
+    from repro.api import ShardedFleetBackend
+    kw = {"overlap": False} if plane == "sync" else {}
+    if plane == "sharded":
+        kw = {"shard_dispatch": True,
+              "backend": ShardedFleetBackend(capacity=6, window=8,
+                                             dim=CFG.d_embed)}
+    gw = StreamSplitGateway(CFG, params, policy=TierPolicy(L), capacity=6,
+                            window=8, qos_reserve=0, **kw)
+    rng = np.random.default_rng(5)
+    sids = [gw.open_session().sid for _ in range(6)]
+    for t in range(2):
+        for sid in sids:
+            gw.submit(sid, FrameRequest(t=t, mel=_mel(rng)))
+        assert sorted({r.k for r in gw.tick()}) == [0, L // 2, L]
+        assert gw.stats().engine_launches_per_tick == per_tick
+    assert gw.registry.value("gateway_engine_launches") == 2 * per_tick
+
+
+def test_streaming_tick_sizes_build_one_program_per_k_and_bucket(params):
+    """Ticks of every size 1..64 with mixed k build one chain per k, and
+    jit compiles each chain once per padded bucket size it saw: no
+    program per tick size or per bucket tuple inside the split engine."""
+    from repro.core.fleet import pad_pow2
+    n = 64
+    gw = StreamSplitGateway(CFG, params, policy=TierPolicy(L), capacity=n,
+                            window=8, qos_reserve=0)
+    rng = np.random.default_rng(21)
+    sids = [gw.open_session().sid for _ in range(n)]
+    pads: dict[int, set] = {}
+    for size in range(1, n + 1):
+        for sid in rng.permutation(sids)[:size]:
+            gw.submit(int(sid), FrameRequest(t=size, mel=_mel(rng)))
+        for r in gw.tick():
+            pads.setdefault(r.k, set()).add(pad_pow2(r.bucket_size))
+    eng = gw.engine
+    assert set(eng._chain) == set(pads) == {0, L // 2, L}
+    for k, seen in pads.items():
+        assert eng._chain[k]._cache_size() == len(seen), f"k={k}"
+    assert not eng._server and set(eng._edge) == {L}
+
+
 def test_tick_launch_requires_overlapped_plane(params):
     gw = StreamSplitGateway(CFG, params, policy=FixedKPolicy(L, 1),
                             capacity=2, qos_reserve=0, overlap=False)

@@ -33,6 +33,95 @@ def test_engine_builds_executables_lazily(enc_setup):
     assert set(eng._edge) == {2, cfg.n_blocks} and set(eng._server) == {2}
 
 
+_L = 4   # enc_setup's n_blocks
+
+
+@pytest.fixture(scope="module")
+def engines(enc_setup):
+    """One engine per wire format, shared so each (k, B) compiles once."""
+    cfg, _ = enc_setup
+    return {q: SplitEngine(cfg, quantize_wire=q) for q in (True, False)}
+
+
+@pytest.mark.parametrize("quantize", [True, False])
+@pytest.mark.parametrize("B", [1, 5, 16])
+@pytest.mark.parametrize("k", [0, 1, _L // 2, _L - 1, _L])
+def test_run_batch_async_bitwise_equals_three_launch_chain(
+        enc_setup, engines, k, B, quantize):
+    """The fused per-k executable serves the bits of the edge stage, the
+    Pallas wire kernel and the server stage launched one by one, and the
+    wire bytes ``run_batch`` reports.  Pinned on these inputs: inside the
+    larger module XLA compiles the stages and the interpreted kernel anew,
+    and on other inputs the two may differ in the last bit."""
+    from repro.kernels import ops as kernel_ops
+    cfg, params = enc_setup
+    assert cfg.n_blocks == _L
+    eng = engines[quantize]
+    mel = jax.random.normal(jax.random.PRNGKey(10 * k + B),
+                            (B, cfg.frames, cfg.n_mels))
+    z, wire = eng.run_batch_async(params, mel, k)
+    want = eng._edge_exec(k)(params, mel)
+    if k < _L:
+        if quantize:
+            want = kernel_ops.wire_roundtrip(want)
+        want = eng._server_exec(k)(params, want)
+    np.testing.assert_array_equal(np.asarray(z), np.asarray(want))
+    assert wire == eng.run_batch(params, mel, k)[1]
+
+
+def test_run_batch_async_launches_one_executable_every_k(enc_setup):
+    """One launch per call at every k, counted by ``launches``; the
+    per-stage server executables are never built on this path, while
+    the ``run_batch`` reference still launches edge, wire and server."""
+    cfg, params = enc_setup
+    eng = SplitEngine(cfg)
+    mel = jax.random.normal(jax.random.PRNGKey(3), (2, cfg.frames,
+                                                    cfg.n_mels))
+    for k in range(cfg.n_blocks + 1):
+        n0 = eng.launches
+        eng.run_batch_async(params, mel, k)
+        assert eng.launches - n0 == 1, f"k={k}"
+    assert set(eng._chain) == set(range(cfg.n_blocks + 1))
+    assert not eng._server and set(eng._edge) == {cfg.n_blocks}
+    for k, want in ((0, 3), (2, 3), (cfg.n_blocks, 1)):
+        n0 = eng.launches
+        eng.run_batch(params, mel, k)
+        assert eng.launches - n0 == want, f"k={k}"
+
+
+@pytest.mark.parametrize("method", ["run", "run_batch", "run_batch_async"])
+def test_launches_counts_each_executable_called(enc_setup, method):
+    """``launches`` moves by exactly the number of compiled executables
+    the call invoked, counted by spies on every cached executable."""
+    cfg, params = enc_setup
+    eng = SplitEngine(cfg)
+    mel = jax.random.normal(jax.random.PRNGKey(4), (2, cfg.frames,
+                                                    cfg.n_mels))
+    called = []
+
+    def spy(exe, name):
+        if getattr(exe, "spied", False):
+            return exe
+        def wrapped(*args):
+            called.append(name)
+            return exe(*args)
+        wrapped.spied = True
+        return wrapped
+
+    run = getattr(eng, method)
+    eng._qdq_tensor = spy(eng._qdq_tensor, "qdq")
+    eng._qdq_sample = spy(eng._qdq_sample, "qdq")
+    for k in (0, _L // 2, _L):
+        run(params, mel, k)                  # build this k's executables
+        for cache in (eng._edge, eng._server, eng._chain):
+            for kk in list(cache):
+                cache[kk] = spy(cache[kk], kk)
+        called.clear()
+        n0 = eng.launches
+        run(params, mel, k)
+        assert called and eng.launches - n0 == len(called), (k, called)
+
+
 def test_split_exact_every_k_fp32(enc_setup):
     cfg, params = enc_setup
     eng = SplitEngine(cfg, quantize_wire=False)

@@ -13,6 +13,7 @@ libtpu at a time: under pytest-xdist every worker imports this file, and
 only the worker that runs these tests loads the library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -80,3 +81,30 @@ def test_split_stage_compiles(stage, one_chip, side, k):
                                  sharding=one_chip)
         compiled = engine._server_exec(k).lower(params, x).compile()
         assert compiled.out_info.shape == (B, CFG.d_embed)
+
+
+@pytest.fixture
+def mosaic_wire(monkeypatch):
+    """Trace the wire kernel for the chip (Mosaic), as it is traced on
+    the TPU: ``ops._resolve`` picks the interpreter from the default
+    backend, which is the CPU here.  The kernel's trace cache is dropped
+    on both sides, so no CPU trace is reused now and this one is not
+    reused later."""
+    monkeypatch.setattr(ops, "_resolve", lambda interpret: False)
+    ops.wire_roundtrip.clear_cache()
+    yield
+    ops.wire_roundtrip.clear_cache()
+
+
+# run_batch_async's one executable per k < L: edge stage, the wire kernel
+# and server stage in one module, so Mosaic must lower the kernel there.
+# The kernel's op keeps the name of its jit wrapper, which is what a trace
+# reader finds it by inside the fused module.
+@pytest.mark.parametrize("k", [0, CFG.n_blocks // 2])
+def test_fused_chain_compiles(stage, mosaic_wire, k):
+    engine, params, mel = stage
+    compiled = engine._chain_exec(k).lower(params, mel).compile()
+    text = compiled.as_text()
+    assert re.search(r"%wire_roundtrip(\.\d+)? = \S+ custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', text)
+    assert compiled.out_info.shape == (B, CFG.d_embed)
